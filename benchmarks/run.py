@@ -114,6 +114,7 @@ def main() -> None:
     )
     from repro.launch import runtime
 
+    runtime.enable_compile_cache()
     suites = {
         "gemm": gemm_table1.run,
         "svd": svd_fig34.run,

@@ -55,3 +55,10 @@ class TaskError(AlchemistError):
     """Asynchronous task-queue failures: a future that timed out, a queue
     used after close, or a pending handle whose producing task failed
     (the original exception is chained as ``__cause__``)."""
+
+
+class QueueClosedError(TaskError, SessionError):
+    """Work submitted to a task queue after it closed. A session's queue
+    closes with the session, so this is also a session-lifecycle error: a
+    client whose session a server stop closes mid-request sees a
+    ``SessionError`` whichever check the stop wins against."""

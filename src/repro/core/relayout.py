@@ -319,7 +319,7 @@ def transfer_cost(
 # ---------------------------------------------------------------------------
 #
 # ``jax.device_put`` into a NamedSharding (the bridge's send path) requires
-# each sharded dim to be divisible by its shard count on jax 0.4.x, so e.g. a
+# each sharded dim to be divisible by its shard count, so e.g. a
 # 6x6 matrix could not be sent to a 4-worker session. The bridge lifts this by
 # padding each dim up to the next multiple of its destination shard count with
 # zero rows/cols before ``device_put`` and slicing the padding back off on

@@ -19,7 +19,7 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.layouts import AXIS_DATA, AXIS_MODEL, AXIS_POD
 
@@ -156,7 +156,7 @@ class ShardingRules:
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
     """Build a mesh from the available devices (works on the 1-CPU test env
     when shape == (1,)*n, and on the 512-host-device dry-run env)."""
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def single_device_mesh() -> Mesh:

@@ -22,7 +22,7 @@ import threading
 import time
 from typing import Any, Callable, Optional
 
-from repro.core.errors import TaskError
+from repro.core.errors import QueueClosedError, TaskError
 from repro.core.futures import AlFuture
 
 _SHUTDOWN = object()
@@ -57,7 +57,7 @@ class TaskQueue:
         future = AlFuture(label=label or getattr(fn, "__name__", "task"))
         with self._lock:
             if self._closed:
-                raise TaskError(f"TaskQueue {self.name!r} is closed")
+                raise QueueClosedError(f"TaskQueue {self.name!r} is closed")
             self.tasks_submitted += 1
             self._q.put((fn, future))
             self.max_backlog = max(self.max_backlog, self._q.qsize())

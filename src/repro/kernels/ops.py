@@ -1,9 +1,10 @@
 """Dispatching wrappers — the public kernel API the rest of the framework uses.
 
-On TPU, calls lower to the Pallas kernels; elsewhere (this CPU container,
-unit tests) they run the pure-jnp oracles in :mod:`repro.kernels.ref`. Set
+On TPU, calls lower to the Pallas kernels; elsewhere (CPU hosts, unit
+tests) they run the pure-jnp oracles in :mod:`repro.kernels.ref`. Set
 ``REPRO_FORCE_PALLAS=interpret`` to exercise the kernel bodies on CPU via
-interpret mode (used by the kernel test suite).
+interpret mode (used by the kernel test suite). A kernel that cannot lower
+raises: there is no silent fallback to the oracles on TPU.
 
 The dispatch is deliberately *per-call-site static* (a module-level backend
 probe), so jitted programs never trace both paths.
@@ -30,11 +31,7 @@ def backend() -> str:
         return "pallas-interpret"
     if _FORCE in ("1", "true", "tpu"):
         return "pallas"
-    try:
-        plat = jax.default_backend()
-    except Exception:  # pragma: no cover - no devices at all
-        plat = "cpu"
-    return "pallas" if plat == "tpu" else "ref"
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
 _BACKEND = backend()
@@ -127,12 +124,9 @@ def ssd_scan(
 
 def _fusable(x) -> bool:
     """Pallas pad/strip take one device's buffer: numpy hosts and
-    single-device jax arrays qualify; sharded arrays fall back to ref."""
+    single-device jax arrays qualify; sharded arrays take the jnp path."""
     if isinstance(x, jax.Array):
-        try:
-            return len(x.sharding.device_set) == 1
-        except Exception:  # pragma: no cover - exotic array types
-            return False
+        return len(x.sharding.device_set) == 1
     return True  # numpy / python buffers: pallas_call will device_put them
 
 
@@ -144,12 +138,7 @@ def pad_to(x, physical_shape: Tuple[int, int]):
     The plan cache records the path so benchmarks can attribute fusion.
     """
     if use_pallas() and _fusable(x):
-        try:
-            return _relayout_pad.pad_to(x, tuple(physical_shape), interpret=_interp()), _BACKEND
-        except ValueError:
-            raise
-        except Exception:  # lowering/compile failure: fall back to the oracle
-            pass
+        return _relayout_pad.pad_to(x, tuple(physical_shape), interpret=_interp()), _BACKEND
     return _ref.pad_to(x, tuple(physical_shape)), "ref"
 
 
@@ -159,12 +148,7 @@ def strip_to(x, logical_shape: Tuple[int, int]):
     Returns ``(stripped, path)`` — same contract as :func:`pad_to`.
     """
     if use_pallas() and _fusable(x):
-        try:
-            return _relayout_pad.strip_to(x, tuple(logical_shape), interpret=_interp()), _BACKEND
-        except ValueError:
-            raise
-        except Exception:
-            pass
+        return _relayout_pad.strip_to(x, tuple(logical_shape), interpret=_interp()), _BACKEND
     return _ref.strip_to(x, tuple(logical_shape)), "ref"
 
 

@@ -11,10 +11,14 @@ tiled device pass (DESIGN.md §10), following the grid idiom of
   shares the output block's index map, so edge tiles read out of bounds; a
   ``broadcasted_iota`` mask against the logical extent selects real values
   and writes zeros elsewhere — OOB reads never reach the output.
-- :func:`strip_to` grids over the *logical* output with a block that divides
-  the physical input dims, so every input read is in bounds; partial edge
-  output tiles are write-masked by Pallas automatically and the body is a
-  straight block copy.
+- :func:`strip_to` grids over the *logical* output with the same block on
+  both sides; partial edge output tiles are write-masked by Pallas and the
+  body is a straight block copy.
+
+Blocks follow Mosaic's tiling rule: the row block is a multiple of
+:data:`ROW_ALIGN` and the column block a multiple of :data:`COL_ALIGN`, or
+the full extent where input and output share it. Partial edge blocks (and a
+block larger than a small array) are legal; the masks above keep them exact.
 
 Bit-exactness against :mod:`repro.kernels.ref` is property-tested in
 tests/test_padded_roundtrip.py; ``ops.py`` dispatches here on TPU (or under
@@ -24,24 +28,36 @@ tests/test_padded_roundtrip.py; ``ops.py`` dispatches here on TPU (or under
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK = 128
+#: Sublane / lane tiling: 32 rows covers the packed 8/16/32-row tiles of
+#: 32-, 16- and 8-bit dtypes; 128 columns is one lane tile.
+ROW_ALIGN = 32
+COL_ALIGN = 128
+#: Block caps: a 512x512 f32 block is 1 MiB, so input + output blocks,
+#: double-buffered, take 4 MiB of VMEM.
+BLOCK_ROWS = 512
+BLOCK_COLS = 512
 
 
-def _pick_block(dim: int, cap: int = DEFAULT_BLOCK) -> int:
-    """Largest divisor of ``dim`` not exceeding ``cap`` — blocks that divide
-    the physical extent keep strip_to's reads in bounds and pad_to's grid
-    exact."""
-    dim = max(int(dim), 1)
-    for cand in range(min(cap, dim), 0, -1):
-        if dim % cand == 0:
-            return cand
-    return 1  # pragma: no cover - range always yields 1
+def _pick_block(logical: int, physical: int, align: int, cap: int) -> int:
+    """One block dim for an axis whose input and output extents are
+    ``logical`` and ``physical`` (in either order): the shared full extent
+    when it is small enough, else ``cap`` or the aligned extent."""
+    if logical == physical and physical <= cap:
+        return physical
+    return min(cap, -(-max(logical, physical) // align) * align)
+
+
+def _blocks(logical: Tuple[int, int], physical: Tuple[int, int]) -> Tuple[int, int]:
+    return (
+        _pick_block(logical[0], physical[0], ROW_ALIGN, BLOCK_ROWS),
+        _pick_block(logical[1], physical[1], COL_ALIGN, BLOCK_COLS),
+    )
 
 
 def _pad_kernel(x_ref, o_ref, *, m: int, n: int, bm: int, bn: int):
@@ -56,13 +72,9 @@ def _strip_kernel(x_ref, o_ref):
     o_ref[...] = x_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("physical_shape", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("physical_shape", "interpret"))
 def pad_to(
-    x: jax.Array,
-    physical_shape: Tuple[int, int],
-    *,
-    block: Optional[Tuple[int, int]] = None,
-    interpret: bool = False,
+    x: jax.Array, physical_shape: Tuple[int, int], *, interpret: bool = False
 ) -> jax.Array:
     """Zero-pad ``x`` [m, n] up to ``physical_shape`` [mp, np] in one pass."""
     m, n = x.shape
@@ -71,11 +83,11 @@ def pad_to(
         return x
     if mp < m or np_ < n:
         raise ValueError(f"cannot pad {x.shape} down to {physical_shape}")
-    bm, bn = block or (_pick_block(mp), _pick_block(np_))
+    bm, bn = _blocks((m, n), (mp, np_))
     kern = functools.partial(_pad_kernel, m=m, n=n, bm=bm, bn=bn)
     return pl.pallas_call(
         kern,
-        grid=(mp // bm, np_ // bn),
+        grid=(pl.cdiv(mp, bm), pl.cdiv(np_, bn)),
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
@@ -84,13 +96,9 @@ def pad_to(
     )(x)
 
 
-@functools.partial(jax.jit, static_argnames=("logical_shape", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("logical_shape", "interpret"))
 def strip_to(
-    x: jax.Array,
-    logical_shape: Tuple[int, int],
-    *,
-    block: Optional[Tuple[int, int]] = None,
-    interpret: bool = False,
+    x: jax.Array, logical_shape: Tuple[int, int], *, interpret: bool = False
 ) -> jax.Array:
     """Slice the divisibility padding off ``x`` [mp, np] down to [m, n]."""
     mp, np_ = x.shape
@@ -99,7 +107,7 @@ def strip_to(
         return x
     if m > mp or n > np_:
         raise ValueError(f"cannot strip {x.shape} up to {logical_shape}")
-    bm, bn = block or (_pick_block(mp), _pick_block(np_))
+    bm, bn = _blocks((m, n), (mp, np_))
     return pl.pallas_call(
         _strip_kernel,
         grid=(pl.cdiv(m, bm), pl.cdiv(n, bn)),

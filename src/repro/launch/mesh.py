@@ -18,7 +18,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.core.layouts import AXIS_DATA, AXIS_MODEL, AXIS_POD
 
@@ -29,10 +29,10 @@ MULTI_POD_SHAPE: Tuple[int, int, int] = (2, 16, 16)
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = MULTI_POD_SHAPE if multi_pod else SINGLE_POD_SHAPE
     axes = (AXIS_POD, AXIS_DATA, AXIS_MODEL) if multi_pod else (AXIS_DATA, AXIS_MODEL)
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2)) -> Mesh:
     """Small mesh for CPU multi-device tests (requires forced host devices)."""
     axes = ((AXIS_POD, AXIS_DATA, AXIS_MODEL) if len(shape) == 3 else (AXIS_DATA, AXIS_MODEL))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

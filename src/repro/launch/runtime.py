@@ -13,13 +13,21 @@ environment drift instead of code.
 ``LD_PRELOAD`` only takes effect at process start, so :func:`ensure_tuned`
 re-execs the interpreter once with the tuned environment (guarded by a
 sentinel variable); ``benchmarks/run.py --tuned`` is the caller.
+
+:func:`enable_compile_cache` places JAX's persistent compilation cache; the
+launchers (``chip_smoke.py``, ``benchmarks/run.py``) call it before their
+first compile.
 """
 
 from __future__ import annotations
 
 import os
 import sys
+from pathlib import Path
 from typing import Dict, Optional
+
+#: the repository checkout this module was loaded from (src/repro/launch/..)
+CHECKOUT = Path(__file__).resolve().parents[3]
 
 #: sentinel marking "this process was re-exec'd with the tuned env"
 _SENTINEL = "REPRO_TUNED"
@@ -85,6 +93,24 @@ def ensure_tuned(device_count: int = 8) -> None:
     if cwd not in pythonpath.split(os.pathsep):
         env["PYTHONPATH"] = f"{cwd}{os.pathsep}{pythonpath}" if pythonpath else cwd
     os.execve(sys.executable, [sys.executable] + sys.argv, env)
+
+
+def enable_compile_cache() -> str:
+    """Put JAX's persistent compilation cache in a fixed place; returns it.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins and JAX reads it itself, so
+    nothing is set in code. Otherwise the cache is ``<checkout>/.jax_cache``:
+    a path that never moves between runs, since the path is part of what a
+    cached entry is found by. Call before the first compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _loaded_allocator() -> str:

@@ -30,16 +30,6 @@ from repro.core.layouts import AXIS_DATA, AXIS_MODEL, AXIS_POD, GRID
 from repro.core import sharding as shardcore
 from repro.kernels import ops
 
-# jax >= 0.5 exposes shard_map / lax.pvary at top level; 0.4.x has shard_map
-# under experimental and no pvary (replication tracking arrived later, so the
-# identity is a sound stand-in there).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax 0.4.x only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
 
 def _row_axes(mesh: Mesh):
     return tuple(a for a in (AXIS_POD, AXIS_DATA) if a in mesh.axis_names)
@@ -137,16 +127,17 @@ def summa(
             return acc + ops.matmul(a_panel, b_panel, out_dtype=jnp.float32)
 
         acc = jnp.zeros((m_loc, k_loc), jnp.float32)
-        # mark the carry as device-varying so the fori_loop carry types match
-        acc = _pvary(acc, tuple(mesh.axis_names))
         acc = jax.lax.fori_loop(0, n_panels, body, acc)
         return acc.astype(a_loc.dtype)
 
-    c_p = _shard_map(
+    # check_vma=False: the local GEMM may be a Pallas kernel, whose output
+    # carries no varying-axes type for shard_map to check.
+    c_p = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(grid_spec, grid_spec),
         out_specs=grid_spec,
+        check_vma=False,
     )(a_p, b_p)
     return c_p[:m, :k]
 
@@ -176,8 +167,12 @@ def gemm_allgather(a: jax.Array, b: jax.Array, mesh: Mesh) -> jax.Array:
         b_col = jax.lax.all_gather(b_loc, row_axes, axis=0, tiled=True)
         return ops.matmul(a_row, b_col)
 
-    c_p = _shard_map(
-        local, mesh=mesh, in_specs=(grid_spec, grid_spec), out_specs=grid_spec
+    c_p = jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(grid_spec, grid_spec),
+        out_specs=grid_spec,
+        check_vma=False,
     )(a_p, b_p)
     return c_p[:m, :k]
 

@@ -21,16 +21,6 @@ from jax.sharding import Mesh, NamedSharding
 
 from repro.core.layouts import ROW
 
-# jax >= 0.5 exposes shard_map at top level (replication check kw: check_vma);
-# 0.4.x has it under experimental (kw: check_rep).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _NOCHECK_KW = {"check_vma": False}
-else:  # pragma: no cover - exercised on jax 0.4.x only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _NOCHECK_KW = {"check_rep": False}
-
 
 def _all_axes(mesh: Mesh):
     return tuple(mesh.axis_names)
@@ -81,8 +71,8 @@ def tsqr(a: jax.Array, mesh: Mesh, *, tree: bool = False) -> Tuple[jax.Array, ja
         return q * sign[None, :], r_final * sign[:, None]
 
     def _flat_rank(axis_names):
-        # Axis sizes come from the (statically known) mesh: jax 0.4.x has no
-        # jax.lax.axis_size, and the sizes are compile-time constants anyway.
+        # Axis sizes come from the (statically known) mesh: they are
+        # compile-time constants.
         sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         rank = jax.lax.axis_index(axis_names[0])
         for ax in axis_names[1:]:
@@ -118,12 +108,12 @@ def tsqr(a: jax.Array, mesh: Mesh, *, tree: bool = False) -> Tuple[jax.Array, ja
         # over the lexicographic rank by permuting each axis jointly.
         return jax.lax.ppermute(x, axis_names, perm)
 
-    q, r_rep = _shard_map(
+    q, r_rep = jax.shard_map(
         lambda a_loc: local(a_loc),
         mesh=mesh,
         in_specs=(spec,),
         out_specs=(spec, jax.sharding.PartitionSpec(None, None)),
         # R is replicated by construction (gathered QR)
-        **_NOCHECK_KW,
+        check_vma=False,
     )(a_p)
     return q[:m], r_rep

@@ -90,8 +90,6 @@ def analyze_compiled(
     compile_seconds: float = 0.0,
 ) -> RooflineReport:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     flops_pd = float(ca.get("flops", 0.0))
     bytes_pd = float(ca.get("bytes accessed", 0.0))
 

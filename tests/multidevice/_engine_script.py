@@ -56,7 +56,7 @@ s_ref = np.linalg.svd(a, compute_uv=False)[:4]
 np.testing.assert_allclose(np.asarray(s), s_ref, rtol=0.05)
 
 # TSQR on a 2x2 grid (regression: _flat_rank used jax.lax.axis_size, which
-# jax 0.4.x lacks — multi-axis meshes crashed)
+# older jax lacked — multi-axis meshes crashed)
 hq, hr = ac1.run("elemental", "tsqr", h1)
 r_np = np.asarray(ac1.collect(hr))
 np.testing.assert_allclose(r_np.T @ r_np, a.T @ a, atol=2e-2)

@@ -8,14 +8,16 @@ assert "--xla_force_host_platform_device_count=8" in os.environ.get("XLA_FLAGS",
 import dataclasses
 import numpy as np
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 
 from repro.configs import InputShape, get_config
 from repro.core.layouts import AXIS_DATA, AXIS_MODEL, AXIS_POD
 from repro.models import build_model
 from repro.models.registry import make_batch
 
-mesh = jax.make_mesh((2, 2, 2), (AXIS_POD, AXIS_DATA, AXIS_MODEL))
+mesh = jax.make_mesh(
+    (2, 2, 2), (AXIS_POD, AXIS_DATA, AXIS_MODEL), axis_types=(AxisType.Auto,) * 3
+)
 single = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), (AXIS_DATA, AXIS_MODEL))
 
 shape = InputShape("md", seq_len=32, global_batch=4, kind="train")

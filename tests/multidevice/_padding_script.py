@@ -49,16 +49,21 @@ for workers in (2, 4, 8):
     ac = repro.AlchemistContext(engine, num_workers=workers, name=f"pad{workers}")
     if HAVE_HYPOTHESIS:
 
-        @given(
-            m=st.integers(min_value=1, max_value=24),
-            n=st.integers(min_value=1, max_value=12),
-            seed=st.integers(min_value=0, max_value=2**31 - 1),
-        )
-        @settings(max_examples=25, deadline=None)
-        def prop(m, n, seed, ac=ac, workers=workers):
-            roundtrip(ac, workers, m, n, seed)
+        def make_prop(ac, workers):
+            # hypothesis refuses @given on a function with defaults, so the
+            # session and group size are closed over instead of bound.
+            @given(
+                m=st.integers(min_value=1, max_value=24),
+                n=st.integers(min_value=1, max_value=12),
+                seed=st.integers(min_value=0, max_value=2**31 - 1),
+            )
+            @settings(max_examples=25, deadline=None)
+            def prop(m, n, seed):
+                roundtrip(ac, workers, m, n, seed)
 
-        prop()
+            return prop
+
+        make_prop(ac, workers)()
     else:
         for m, n in [(1, 1), (2, 5), (6, 6), (7, 3), (13, 9), (16, 8), (workers - 1, 3)]:
             roundtrip(ac, workers, m, n, seed=m * 100 + n)

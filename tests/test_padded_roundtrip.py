@@ -185,6 +185,8 @@ def test_fused_pad_strip_matches_ref(m, n, r, c, dtype, seed):
         (2, 5, 4, 2),  # m < row shards
         (7, 3, 3, 5),  # nothing divides anything
         (5, 5, 1, 1),  # single worker: zero pads
+        (1030, 7, 4, 1),  # rows span several blocks, partial last block
+        (9, 1000, 2, 3),  # columns span several blocks, partial last block
     ],
 )
 @pytest.mark.parametrize("dtype", DTYPES)

@@ -116,8 +116,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import repro.launch.dryrun as dr
 import jax
+from jax.sharding import AxisType
 from repro.core.layouts import AXIS_DATA, AXIS_MODEL
-mesh = jax.make_mesh((2, 4), (AXIS_DATA, AXIS_MODEL))
+mesh = jax.make_mesh((2, 4), (AXIS_DATA, AXIS_MODEL), axis_types=(AxisType.Auto,) * 2)
 import repro.configs.base as base
 import dataclasses
 # shrink shapes so the mini run is quick
