@@ -835,7 +835,7 @@ class ClientCore:
                         call_kwargs["mesh"] = sess.mesh
 
                     t0 = time.perf_counter()
-                    with sess.mesh:
+                    with sess.mesh, jax.profiler.TraceAnnotation("al.routine", routine=label):
                         result = r.fn(*call_args, **call_kwargs)
                     if block:
                         result = jax.block_until_ready(result)

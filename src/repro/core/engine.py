@@ -313,8 +313,9 @@ class AlchemistEngine:
     def stats(self) -> Dict[str, Any]:
         """One merged engine snapshot (DESIGN.md §9/§12): the worker pool and
         admission queue, every live session's ``SessionStats`` (plus its
-        resolved placement ticket), the engine-wide governor (``pressure()``,
-        budget, high water), the resident store, and the scheduler section
+        resolved placement ticket and its task queue's counters), the
+        engine-wide governor (``pressure()``, budget, high water), the
+        resident store, and the scheduler section
         (queue depth, ticket lifecycle counters, shared groups, scoring
         hits). This is what ``benchmarks/run.py --json`` embeds."""
         self._snapshot_seq += 1
@@ -340,6 +341,7 @@ class AlchemistEngine:
                         s.placement.summary() if s.placement is not None else None
                     ),
                     **s.stats.summary(),
+                    "tasks": s.tasks.stats(),
                 }
                 for sid, s in sessions.items()
             },

@@ -39,6 +39,7 @@ import itertools
 from typing import TYPE_CHECKING, Any, Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.errors import ShapeError
 
@@ -279,7 +280,8 @@ def content_key(array: Any) -> Tuple:
         # reassembly copy.
         return key_fn()
     arr = np.asarray(array)
-    digest = hashlib.sha1(np.ascontiguousarray(arr).tobytes()).hexdigest()
+    with TraceAnnotation("al.store.key", nbytes=arr.nbytes, side="host"):
+        digest = hashlib.sha1(np.ascontiguousarray(arr).tobytes()).hexdigest()
     return (tuple(int(d) for d in arr.shape), str(arr.dtype), digest)
 
 
@@ -321,7 +323,8 @@ class SendExpr(Expr):
         # the redundant O(m·n) copy.
         if isinstance(array, np.ndarray):
             if snapshot:
-                array = np.array(array)  # fresh copy
+                with TraceAnnotation("al.host.copy", nbytes=array.nbytes, site="snapshot"):
+                    array = np.array(array)  # fresh copy
         elif not hasattr(array, "shape"):
             array = np.array(array)  # lists etc.: conversion already copies
         arr = array

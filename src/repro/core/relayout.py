@@ -605,26 +605,27 @@ def timed_relayout(
     handle so logical reads slice the zeros back off (the send path's choice
     — a resident matrix keeps its put-legal physical form for cheap refills).
     """
-    hit = False
-    pads = (0, 0)
-    fused = False
-    if cache is not None:
-        plan, hit = cache.plan(tuple(x.shape), x.dtype, src, dst, mesh)
-        cost = plan.cost
-        pads = plan.pads
-        t0 = time.perf_counter()
-        out = plan.apply(x)
-        if strip:
-            out = plan.strip(out)
-            pads = (0, 0)
-        fused = plan.fused_path in FUSED_PATHS
-    else:
-        cost = transfer_cost(tuple(x.shape), x.dtype, src, dst, mesh)
-        t0 = time.perf_counter()
-        out = relayout(x, dst, mesh, src=src)  # pads + strips internally
-    if block:
-        out.block_until_ready()
-    dt = time.perf_counter() - t0
-    return out, TransferRecord(
-        direction=direction, cost=cost, seconds=dt, cache_hit=hit, pads=pads, fused=fused
-    )
+    with jax.profiler.TraceAnnotation("al.relayout", direction=direction, nbytes=x.nbytes):
+        hit = False
+        pads = (0, 0)
+        fused = False
+        if cache is not None:
+            plan, hit = cache.plan(tuple(x.shape), x.dtype, src, dst, mesh)
+            cost = plan.cost
+            pads = plan.pads
+            t0 = time.perf_counter()
+            out = plan.apply(x)
+            if strip:
+                out = plan.strip(out)
+                pads = (0, 0)
+            fused = plan.fused_path in FUSED_PATHS
+        else:
+            cost = transfer_cost(tuple(x.shape), x.dtype, src, dst, mesh)
+            t0 = time.perf_counter()
+            out = relayout(x, dst, mesh, src=src)  # pads + strips internally
+        if block:
+            out.block_until_ready()
+        dt = time.perf_counter() - t0
+        return out, TransferRecord(
+            direction=direction, cost=cost, seconds=dt, cache_hit=hit, pads=pads, fused=fused
+        )

@@ -29,9 +29,10 @@ Two engine-scoped services are *viewed* rather than owned (DESIGN.md §7/§8):
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
-from typing import Any, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional
 
 import jax
 from jax.sharding import Mesh
@@ -47,6 +48,10 @@ from repro.core.resident import ResidentStore
 from repro.core.taskqueue import TaskQueue
 
 _SESSION_IDS = itertools.count(1)
+
+#: How many of the latest transfers a session keeps for inspection; the
+#: counters above it cover the session's whole life.
+TRANSFER_LOG = 256
 
 
 @dataclasses.dataclass
@@ -89,7 +94,9 @@ class SessionStats:
     spill_overlap_ns: int = 0  # of those, ns the queue worker was computing
     transfer_queue_depth: int = 0  # max transfer-ring depth observed at submit
     fused_relayouts: int = 0  # pad/strip ops served by the fused Pallas kernel
-    transfers: List[TransferRecord] = dataclasses.field(default_factory=list)
+    transfers: Deque[TransferRecord] = dataclasses.field(
+        default_factory=lambda: collections.deque(maxlen=TRANSFER_LOG)
+    )
 
     def record_transfer(self, rec: TransferRecord) -> None:
         self.transfers.append(rec)

@@ -18,14 +18,26 @@ request batches, so the primitive is engine-wide, not Alchemist-specific.
 from __future__ import annotations
 
 import queue
+import re
 import threading
 import time
 from typing import Any, Callable, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.core.errors import QueueClosedError, TaskError
 from repro.core.futures import AlFuture
 
 _SHUTDOWN = object()
+_KIND = re.compile(r"\w+")
+
+
+def span_name(label: str) -> str:
+    """The profiler span of a task: ``al.task.`` and the label's leading word
+    (``send:x`` → ``al.task.send``, ``batch[3]`` → ``al.task.batch``), or
+    ``al.task.task`` where the label starts with none (``<lambda>``)."""
+    kind = _KIND.match(label)
+    return f"al.task.{kind.group() if kind else 'task'}"
 
 
 class TaskQueue:
@@ -50,6 +62,9 @@ class TaskQueue:
         # spill copy-out to measure how much compute the copy hid behind.
         self._busy_total_ns = 0
         self._busy_since: Optional[int] = None
+        # Cumulative ns tasks waited in the queue, from submit to the
+        # worker's pick-up: the queue's "time work waited".
+        self.wait_ns = 0
 
     # -- submission ----------------------------------------------------------
     def submit(self, fn: Callable[[], Any], *, label: str = "") -> AlFuture:
@@ -59,7 +74,7 @@ class TaskQueue:
             if self._closed:
                 raise QueueClosedError(f"TaskQueue {self.name!r} is closed")
             self.tasks_submitted += 1
-            self._q.put((fn, future))
+            self._q.put((fn, future, time.perf_counter_ns()))
             self.max_backlog = max(self.max_backlog, self._q.qsize())
             self._ensure_worker()
         return future
@@ -76,12 +91,12 @@ class TaskQueue:
                 # (it stops at the shutdown sentinel, which is queued last).
                 future = None
             else:
-                future = AlFuture(label=f"{self.name}:barrier")
+                future = AlFuture(label=f"barrier:{self.name}")
                 # Counted as submitted: the worker counts it completed, and
                 # the submitted == completed + failed + pending invariant is
                 # what the soak tests lean on.
                 self.tasks_submitted += 1
-                self._q.put((lambda: None, future))
+                self._q.put((lambda: None, future, time.perf_counter_ns()))
         if future is not None:
             future.result(timeout)
             return
@@ -106,10 +121,14 @@ class TaskQueue:
             try:
                 if item is _SHUTDOWN:
                     return
-                fn, future = item
+                fn, future, submitted = item
                 self._busy_since = time.perf_counter_ns()
+                waited = self._busy_since - submitted
+                self.wait_ns += waited
                 try:
-                    future._set_result(fn())
+                    with TraceAnnotation(span_name(future.label), queued_us=waited / 1e3):
+                        result = fn()
+                    future._set_result(result)
                     self.tasks_completed += 1
                 except BaseException as exc:  # noqa: BLE001 — propagate via future
                     self.tasks_failed += 1
@@ -167,6 +186,7 @@ class TaskQueue:
             "completed": self.tasks_completed,
             "failed": self.tasks_failed,
             "max_backlog": self.max_backlog,
+            "wait_ns": self.wait_ns,
         }
 
     def __repr__(self) -> str:

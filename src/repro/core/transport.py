@@ -36,6 +36,7 @@ import struct
 from typing import TYPE_CHECKING, Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import params as params_codec
 from repro.core.errors import ParameterError, SessionError, TaskError
@@ -371,8 +372,9 @@ class StagedShards:
         import hashlib
 
         h = hashlib.sha1()
-        for slab in self.logical_slabs():
-            h.update(np.ascontiguousarray(slab).data)
+        with TraceAnnotation("al.store.key", nbytes=self.nbytes, side="staged"):
+            for slab in self.logical_slabs():
+                h.update(np.ascontiguousarray(slab).data)
         r, c = self.geom.shape
         return ((int(r), int(c)), str(self.dtype), h.hexdigest())
 
@@ -386,8 +388,9 @@ class StagedShards:
         import jax
 
         t0 = _time.perf_counter()
-        arr = jax.device_put(self.buffers[j], self.geom.devices[j])
-        arr.block_until_ready()
+        with TraceAnnotation("al.device.put", nbytes=self.buffers[j].nbytes):
+            arr = jax.device_put(self.buffers[j], self.geom.devices[j])
+            arr.block_until_ready()
         self._device[j] = arr
         self.put_windows.append((t0, _time.perf_counter()))
 

@@ -82,25 +82,28 @@ def matmul(
     _, n = b.shape
 
     bm_, bn_, bk_ = min(bm, max(m, 1)), min(bn, max(n, 1)), min(bk, max(kdim, 1))
-    ap = _pad_to(a, (bm_, bk_))
-    bp = _pad_to(b, (bk_, bn_))
+    with jax.named_scope("gemm.pad"):
+        ap = _pad_to(a, (bm_, bk_))
+        bp = _pad_to(b, (bk_, bn_))
     mp, kp = ap.shape
     _, np_ = bp.shape
 
-    out = pl.pallas_call(
-        _matmul_kernel,
-        grid=(mp // bm_, np_ // bn_, kp // bk_),
-        in_specs=[
-            pl.BlockSpec((bm_, bk_), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk_, bn_), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        interpret=interpret,
-        name="repro_tiled_matmul",
-    )(ap, bp)
-    return out[:m, :n]
+    with jax.named_scope("gemm.kernel"):
+        out = pl.pallas_call(
+            _matmul_kernel,
+            grid=(mp // bm_, np_ // bn_, kp // bk_),
+            in_specs=[
+                pl.BlockSpec((bm_, bk_), lambda i, j, k: (i, k)),
+                pl.BlockSpec((bk_, bn_), lambda i, j, k: (k, j)),
+            ],
+            out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
+            scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
+            interpret=interpret,
+            name="repro_tiled_matmul",
+        )(ap, bp)
+    with jax.named_scope("gemm.strip"):
+        return out[:m, :n]
 
 
 def vmem_bytes(bm: int, bn: int, bk: int, dtype=jnp.bfloat16) -> int:

@@ -69,17 +69,21 @@ def bidiagonalize(
     def step(carry, i):
         v, u_prev, beta_prev, us, vs = carry
         # u_i = A v_i - beta_i u_{i-1}
-        u = a32 @ v - beta_prev * u_prev
+        with jax.named_scope("lanczos.matvec"):
+            u = a32 @ v - beta_prev * u_prev
         valid_u = (jnp.arange(L) < i).astype(jnp.float32)
-        u = _reorth(u, us, valid_u)
+        with jax.named_scope("lanczos.reorth"):
+            u = _reorth(u, us, valid_u)
         alpha = jnp.linalg.norm(u)
         u = u / jnp.where(alpha > 1e-12, alpha, 1.0)
 
         # v_{i+1} = Aᵀ u_i - alpha_i v_i
-        w = a32.T @ u - alpha * v
+        with jax.named_scope("lanczos.rmatvec"):
+            w = a32.T @ u - alpha * v
         vs_i = vs.at[i].set(v)
         valid_v = (jnp.arange(L) <= i).astype(jnp.float32)
-        w = _reorth(w, vs_i, valid_v)
+        with jax.named_scope("lanczos.reorth"):
+            w = _reorth(w, vs_i, valid_v)
         beta = jnp.linalg.norm(w)
         v_next = w / jnp.where(beta > 1e-12, beta, 1.0)
 
@@ -122,7 +126,9 @@ def truncated_svd_lanczos(
     # B[j,j+1] = beta_j.
     b_small = jnp.diag(alphas) + jnp.diag(betas[:-1], k=1)
 
-    ub, s, vbt = jnp.linalg.svd(b_small, full_matrices=False)
-    u_out = us.T @ ub[:, :k]          # [m, k]
-    v_out = vs.T @ vbt.T[:, :k]       # [n, k]
+    with jax.named_scope("lanczos.small_svd"):
+        ub, s, vbt = jnp.linalg.svd(b_small, full_matrices=False)
+    with jax.named_scope("lanczos.basis"):
+        u_out = us.T @ ub[:, :k]  # [m, k]
+        v_out = vs.T @ vbt.T[:, :k]  # [n, k]
     return u_out.astype(a.dtype), s[:k].astype(a.dtype), v_out.astype(a.dtype)

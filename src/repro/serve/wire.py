@@ -64,6 +64,7 @@ import uuid
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import transport as wire
 from repro.core.errors import AlchemistError, ParameterError, SessionError, TaskError
@@ -461,43 +462,47 @@ class EngineServer:
             # The array body follows on the socket: it must be read on this
             # thread (frames are sequential), shard-direct when the frame
             # declares a geometry this session's layout agrees with.
-            arr, nread = self._recv_send_payload(conn, bound)
-            self.stats["bytes_in"] += nread
-            payload = None
-            if bool(req.get("__has_payload")):
-                # The offload planner wants a host snapshot for the content
-                # store; staged payloads materialize here — the one place a
-                # shard-direct receive pays a full host copy (documented:
-                # plain sends, the hot path, never do).
-                payload = np.asarray(arr)
-            fut = core._local_submit_send(
-                arr,
-                name=str(req.get("__name") or ""),
-                block=bool(req.get("__block")),
-                key=None,
-                payload=payload,
-            )
-            self._reply(conn, cstate, wire.T_OK, {"__ticket": bound.ticket(fut)}, rid)
+            with TraceAnnotation("al.server.send", rid=rid):
+                arr, nread = self._recv_send_payload(conn, bound, rid)
+                self.stats["bytes_in"] += nread
+                payload = None
+                if bool(req.get("__has_payload")):
+                    # The offload planner wants a host snapshot for the content
+                    # store; staged payloads materialize here — the one place a
+                    # shard-direct receive pays a full host copy (documented:
+                    # plain sends, the hot path, never do).
+                    with TraceAnnotation("al.host.copy", nbytes=arr.nbytes, site="payload"):
+                        payload = np.asarray(arr)
+                fut = core._local_submit_send(
+                    arr,
+                    name=str(req.get("__name") or ""),
+                    block=bool(req.get("__block")),
+                    key=None,
+                    payload=payload,
+                )
+                self._reply(conn, cstate, wire.T_OK, {"__ticket": bound.ticket(fut)}, rid)
 
         elif ftype == wire.T_RUN:
-            dec = wire.decode_run_request(
-                req, future_of=bound.future, handle_of=self._lenient_handle(bound)
-            )
-            fut = core._local_submit_run(
-                dec["library"],
-                dec["routine"],
-                dec["args"],
-                dec["params"],
-                block=dec["block"],
-                out_shapes=dec["out_shapes"],
-                out_dtype=dec["out_dtype"],
-            )
-            self._reply(conn, cstate, wire.T_OK, {"__ticket": bound.ticket(fut)}, rid)
+            with TraceAnnotation("al.server.run", rid=rid):
+                dec = wire.decode_run_request(
+                    req, future_of=bound.future, handle_of=self._lenient_handle(bound)
+                )
+                fut = core._local_submit_run(
+                    dec["library"],
+                    dec["routine"],
+                    dec["args"],
+                    dec["params"],
+                    block=dec["block"],
+                    out_shapes=dec["out_shapes"],
+                    out_dtype=dec["out_dtype"],
+                )
+                self._reply(conn, cstate, wire.T_OK, {"__ticket": bound.ticket(fut)}, rid)
 
         elif ftype == wire.T_COLLECT:
-            target = self._target(bound, req)
-            fut = core._local_submit_collect(target)
-            self._reply(conn, cstate, wire.T_OK, {"__ticket": bound.ticket(fut)}, rid)
+            with TraceAnnotation("al.server.collect", rid=rid):
+                target = self._target(bound, req)
+                fut = core._local_submit_collect(target)
+                self._reply(conn, cstate, wire.T_OK, {"__ticket": bound.ticket(fut)}, rid)
 
         elif ftype == wire.T_FETCH:
             fut = bound.future(int(req["__ticket"]))
@@ -512,9 +517,10 @@ class EngineServer:
             )
 
         elif ftype == wire.T_FREE:
-            target = self._target(bound, req)
-            fut = core._local_free_async(target)
-            self._reply(conn, cstate, wire.T_OK, {"__ticket": bound.ticket(fut)}, rid)
+            with TraceAnnotation("al.server.free", rid=rid):
+                target = self._target(bound, req)
+                fut = core._local_free_async(target)
+                self._reply(conn, cstate, wire.T_OK, {"__ticket": bound.ticket(fut)}, rid)
 
         elif ftype == wire.T_BARRIER:
             timeout = req.get("__timeout")
@@ -541,7 +547,7 @@ class EngineServer:
         return bound, False
 
     # -- SEND: shard-direct receive (DESIGN.md §13) ---------------------------
-    def _recv_send_payload(self, conn: socket.socket, bound: _Bound):
+    def _recv_send_payload(self, conn: socket.socket, bound: _Bound, rid: Optional[int]):
         """The ARRAY body following a SEND → (array-or-StagedShards, bytes).
 
         Frames declaring shard-aligned chunking decode straight into staging
@@ -552,14 +558,19 @@ class EngineServer:
         payload array. Mid-stream failure returns every unclaimed slab to
         the pool and re-raises — no handle exists yet, so nothing is
         half-admitted."""
-        from repro.core.relayout import shard_geometry
-
         ftype, meta, n0 = wire.recv_frame(conn)
         if ftype != wire.T_ARRAY:
             raise ParameterError(
                 f"SEND must be followed by an ARRAY frame, got "
                 f"{wire.FRAME_NAMES.get(ftype, ftype)}"
             )
+        with TraceAnnotation("al.wire.recv", rid=rid, nbytes=int(meta.get("__nbytes") or 0)):
+            arr, nbody = self._recv_send_body(conn, bound, meta)
+        return arr, n0 + nbody
+
+    def _recv_send_body(self, conn: socket.socket, bound: _Bound, meta: Dict[str, Any]):
+        from repro.core.relayout import shard_geometry
+
         if meta.get("__shards") and not bound.core.engine_layout.cyclic:
             sess = bound.session
             shape = (int(meta["__rows"]), int(meta["__cols"]))
@@ -584,10 +595,10 @@ class EngineServer:
                 staged = recv.staged
                 staged.on_assembled = self._record_overlap
                 self.stats["shard_direct_receives"] += 1
-                return staged, n0 + nbody
+                return staged, nbody
         arr, nbody = wire.recv_array_body(conn, meta)
         self.stats["reassembly_receives"] += 1
-        return arr, n0 + nbody
+        return arr, nbody
 
     def _record_overlap(self, staged) -> None:
         ratio = staged.overlap_ratio()
@@ -616,7 +627,8 @@ class EngineServer:
                 pass
             return
         try:
-            self._send_fetch_array(conn, cstate, bound, val, rid)
+            with TraceAnnotation("al.wire.fetch", rid=rid, nbytes=getattr(val, "nbytes", 0)):
+                self._send_fetch_array(conn, cstate, bound, val, rid)
         except (ConnectionError, OSError):
             pass  # peer vanished; the connection loop owns teardown
 
@@ -651,7 +663,8 @@ class EngineServer:
     ) -> None:
         slabs = _row_slabs(val)
         if slabs is None:
-            out = np.asarray(val)
+            with TraceAnnotation("al.device.get", nbytes=getattr(val, "nbytes", 0)):
+                out = np.asarray(val)
             self.stats["gathered_fetches"] += 1
             header, chunks, framed = wire.encode_array(out)
             if rid is not None:
@@ -696,7 +709,8 @@ class EngineServer:
 
             def job() -> None:
                 try:
-                    box["v"] = np.asarray(slabs[i][2].data)
+                    with TraceAnnotation("al.device.get", nbytes=slab_bytes[i]):
+                        box["v"] = np.asarray(slabs[i][2].data)
                 finally:
                     ev.set()
 
@@ -997,7 +1011,9 @@ class TcpTransport(Transport):
                 self.bytes_received += nread
                 array = None
                 if rtype == wire.T_ARRAY:
-                    array, nbody = wire.recv_array_body(sock, reply)
+                    nbytes = int(reply.get("__nbytes") or 0)
+                    with TraceAnnotation("al.wire.read", rid=reply.get("__rid"), nbytes=nbytes):
+                        array, nbody = wire.recv_array_body(sock, reply)
                     self.bytes_received += nbody
             except BaseException as exc:  # noqa: BLE001 — fail all, exit
                 err = exc if isinstance(exc, (ConnectionError, OSError)) else (
@@ -1126,13 +1142,17 @@ class TcpTransport(Transport):
             self._max_inflight = max(self._max_inflight, len(self._waiters))
             try:
                 self.frames += 1
-                self.bytes_sent += wire.send_frame(
-                    sock, ftype, {**payload, "__rid": rid}
-                )
-                if array is not None:
-                    self.bytes_sent += wire.send_array(
-                        sock, array, geom=geom, counters=self.counters
-                    )
+                with TraceAnnotation(
+                    "al.wire.write",
+                    rid=rid,
+                    frame=wire.FRAME_NAMES.get(ftype, ftype),
+                    nbytes=0 if array is None else array.nbytes,
+                ):
+                    self.bytes_sent += wire.send_frame(sock, ftype, {**payload, "__rid": rid})
+                    if array is not None:
+                        self.bytes_sent += wire.send_array(
+                            sock, array, geom=geom, counters=self.counters
+                        )
             except BaseException:
                 self._waiters.pop(rid, None)
                 raise

@@ -95,6 +95,21 @@ class TestHandles:
         assert s["send_bytes"] == a.nbytes
         assert s["recv_bytes"] == a.nbytes
 
+    def test_transfer_log_stays_bounded(self, ac, rng):
+        from repro.core.session import TRANSFER_LOG
+
+        a = rng.standard_normal((8, 4)).astype(np.float32)
+        h = ac.send(a)
+        first = ac.stats.transfers[-1]
+        n = TRANSFER_LOG + 10
+        for _ in range(n):
+            ac.stats.record_transfer(first)
+        ac.collect(h)
+        assert len(ac.stats.transfers) == TRANSFER_LOG
+        assert ac.stats.transfers[-1].direction == "receive"  # the latest, as readers take it
+        s = ac.stats.summary()  # the counters still cover every transfer
+        assert (s["num_sends"], s["num_receives"]) == (n + 1, 1)
+
 
 class TestLibraries:
     def test_register_by_import_path(self, ac):

@@ -120,6 +120,18 @@ class TestTaskQueue:
         assert 0 <= stats["max_backlog"] <= 2  # racy: worker may drain eagerly
         q.close()
 
+    def test_wait_ns_counts_time_queued_behind_the_worker(self):
+        q = TaskQueue("t")
+        gate = threading.Event()
+        first = q.submit(gate.wait)
+        second = q.submit(lambda: None)  # queued behind the first
+        time.sleep(0.05)
+        gate.set()
+        first.result(5)
+        second.result(5)
+        assert q.stats()["wait_ns"] >= 50_000_000
+        q.close()
+
     def test_barrier_waits_for_all(self):
         q = TaskQueue("t")
         done = []
