@@ -62,6 +62,7 @@ from repro.core.expr import (
 from repro.core.futures import AlFuture
 from repro.core.handles import AlMatrix
 from repro.core.layouts import GRID, ROW, LayoutSpec
+from repro.core.payload import place, to_default_device
 from repro.core.policy import ExecutionPolicy, PolicyLike, as_policy
 from repro.core.registry import Library, LibrarySpec, load_library
 from repro.core.relayout import (
@@ -294,14 +295,16 @@ class ClientCore:
         name: str,
         block: bool,
         key: Optional[Tuple] = None,
-        payload: Optional[np.ndarray] = None,
+        payload: Optional[Union[np.ndarray, StagedShards]] = None,
     ) -> AlFuture:
         """Engine-side send: content-store attach decision, pending handle,
         governor reservation, task submission. With the engine's resident
         store enabled a content key is derived here for plain sends too, so
         every non-cyclic transfer publishes into the content index — and a
         send whose bytes another session already placed on the engine becomes
-        an attach instead of a bridge crossing."""
+        an attach instead of a bridge crossing. ``payload`` is handed to the
+        store uncopied (``ResidentStore.register``): a ``StagedShards``
+        payload's slabs then belong to the store entry, not the pool."""
         sess = self.session
         store = self._content_store()
         if store is not None:
@@ -389,8 +392,9 @@ class ClientCore:
                     )
                     sess.memgov.charge(h)
                 if staged is not None:
-                    # Slabs go back to the pool unless a zero-copy device_put
-                    # left a live array aliasing them (CPU backends).
+                    # Slabs go back to the pool unless the store adopted them
+                    # as its payload or a zero-copy device_put left a live
+                    # array aliasing them (CPU backends).
                     staged.dispose(x, out)
                 return h
             except BaseException as exc:
@@ -486,21 +490,24 @@ class ClientCore:
                 attached = payload is not None
                 if not attached:
                     # The content died under us: the caller's bytes cross the
-                    # bridge after all. Snapshot them (the caller may mutate
-                    # its array later; the entry payload must stay true to
-                    # the key) and publish so the content is shareable again.
-                    payload = np.array(array)
-                    store.register(key, h, sess, payload=payload)
+                    # bridge after all. Publish them so the content is
+                    # shareable again: received slabs are adopted, an ndarray
+                    # is copied (the caller may mutate it later; the entry
+                    # payload must stay true to the key).
+                    payload = store.register(key, h, sess, payload=array, copy=True).payload
                 sess.memgov.admit(reserve_bytes)
                 admitted = reserve_bytes
-                x = jnp.asarray(payload)
                 # src == dst: the cached plan is a pure placement (pads only),
                 # exactly the governor's refill path.
                 plan, _hit = sess.relayout_cache.plan(
-                    tuple(x.shape), x.dtype, self.engine_layout, self.engine_layout, sess.mesh
+                    tuple(payload.shape),
+                    jax.dtypes.canonicalize_dtype(payload.dtype),
+                    self.engine_layout,
+                    self.engine_layout,
+                    sess.mesh,
                 )
-                out = plan.apply(x)
-                if plan.fused_path in FUSED_PATHS:
+                out, fused = place(payload, plan)
+                if fused:
                     sess.stats.record_fused_relayout()
                 # Engine-side bytes this placement moved (a shared-group view
                 # records none — that is the zero-byte acceptance criterion).
@@ -603,7 +610,7 @@ class ClientCore:
                     live.shape, live.dtype, live.layout, self.client_layout, sess.mesh
                 )
                 t0 = time.perf_counter()
-                out = jnp.asarray(host[: live.shape[0], : live.shape[1]])
+                out = to_default_device(host, live.shape[0], live.shape[1])
                 out.block_until_ready()
                 rec = TransferRecord(
                     direction="receive",

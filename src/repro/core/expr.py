@@ -266,12 +266,22 @@ def peeked_state(val: Any) -> str:
     return val.state if isinstance(val, AlMatrix) else "materialized"
 
 
+def raw_bytes(arr: np.ndarray) -> np.ndarray:
+    """The array's bytes in C (row) order as a flat uint8 array — a view of
+    a C-contiguous input, one copy of any other. A ``memoryview`` cast would
+    refuse dtypes without a buffer format (bfloat16), and ``tobytes()``
+    always copies."""
+    return np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
+
+
 def content_key(array: Any) -> Tuple:
     """Content-identity of a host array: (shape, dtype, sha1 of the bytes).
 
     This keys the planner's per-session resident-matrix cache: two sends of
     equal payloads resolve to one engine-resident matrix, regardless of
-    whether the caller reused the ndarray object or rebuilt it.
+    whether the caller reused the ndarray object or rebuilt it. SHA-1 reads
+    a C-contiguous array in place; row order defines the key, so any other
+    layout is made contiguous first.
     """
     key_fn = getattr(array, "content_key", None)
     if callable(key_fn):
@@ -281,7 +291,7 @@ def content_key(array: Any) -> Tuple:
         return key_fn()
     arr = np.asarray(array)
     with TraceAnnotation("al.store.key", nbytes=arr.nbytes, side="host"):
-        digest = hashlib.sha1(np.ascontiguousarray(arr).tobytes()).hexdigest()
+        digest = hashlib.sha1(raw_bytes(arr)).hexdigest()
     return (tuple(int(d) for d in arr.shape), str(arr.dtype), digest)
 
 

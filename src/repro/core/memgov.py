@@ -74,7 +74,8 @@ import numpy as np
 from repro.core import handles as handles_mod
 from repro.core.errors import HandleError
 from repro.core.handles import AlMatrix
-from repro.core.relayout import FUSED_PATHS, pad_amounts
+from repro.core.payload import aliases_host, place
+from repro.core.relayout import pad_amounts
 from repro.core.taskqueue import TransferExecutor
 
 if TYPE_CHECKING:  # pragma: no cover - typing only, avoids an import cycle
@@ -134,25 +135,6 @@ class _StagingPool:
     def clear(self) -> None:
         with self._lock:
             self._free.clear()
-
-
-def _aliases_host(arr: jax.Array, host: np.ndarray) -> bool:
-    """True if any device shard of ``arr`` shares memory with ``host``. On CPU
-    backends a sharded/donated ``device_put`` of a numpy array is zero-copy —
-    the placed array's backing store IS the host buffer — so a staging buffer
-    aliased by a live device array must never return to the pool: a later
-    spill's gather would write the victim's bytes straight through the alias
-    into the resident matrix."""
-    try:
-        base = host.ctypes.data
-        end = base + host.nbytes
-        for shard in arr.addressable_shards:
-            ptr = shard.data.unsafe_buffer_pointer()
-            if base <= ptr < end:
-                return True
-        return False
-    except Exception:  # pragma: no cover - exotic runtimes: assume aliased
-        return True
 
 
 def _validate_budget(budget: Optional[int]) -> Optional[int]:
@@ -755,21 +737,20 @@ class MemoryGovernor:
                 # either way src == dst, so the cached plan is a pure
                 # placement — no permutation, and pads exactly when the
                 # payload needs them for the device_put. The put consumes the
-                # host buffer directly; only a dtype the device would
+                # host buffer directly (a slab payload slab by slab, see
+                # ``payload.place``); only a dtype the device would
                 # canonicalize anyway (f64 without x64 mode) is converted
                 # host-side first, so the plan key matches the placed array.
                 canon = jax.dtypes.canonicalize_dtype(host.dtype)
-                x = host if canon == host.dtype else np.asarray(host, dtype=canon)
                 plan, _hit = sess.relayout_cache.plan(
-                    tuple(x.shape), canon, h.layout, h.layout, sess.mesh
+                    tuple(host.shape), canon, h.layout, h.layout, sess.mesh
                 )
-                arr = plan.apply(x, donate=True)
-                fused = plan.fused_path in FUSED_PATHS
+                arr, fused = place(host, plan, donate=True)
                 h._data = arr
                 h.pads = (arr.shape[0] - h.shape[0], arr.shape[1] - h.shape[1])
                 h._state = handles_mod.MATERIALIZED
                 popped = self._host_store.pop(h.id, None)
-                if popped is not None and not _aliases_host(arr, popped):
+                if popped is not None and not aliases_host(arr, popped):
                     self._staging.release(popped)  # refused if client-escaped
                 self.settle(claim)  # claim -> charge, atomic: lock is held
                 self.charge(h)

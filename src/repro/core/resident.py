@@ -11,9 +11,11 @@ The store lifts content identity to the engine:
 
 - every non-cyclic send is **published** under its content key
   (:func:`repro.core.expr.content_key`): the entry records the host payload
-  (when the caller can hand one over for free — the planner's snapshotted
-  ``SendExpr`` arrays), plus one *placement* per session that holds the
-  matrix on its worker group;
+  when it comes for free — the planner's snapshotted ``SendExpr`` array
+  in-process, or, over a wire, the received staging slabs adopted as they
+  are (:class:`~repro.core.payload.SlabPayload`; they go back to the
+  staging pool when the entry dies) — plus one *placement* per session that
+  holds the matrix on its worker group;
 - a second session sending byte-identical data **attaches** instead: no
   bytes cross the client↔engine bridge — the engine already has them — and
   the session's placement is a plain engine-internal ``device_put`` from the
@@ -51,10 +53,13 @@ from typing import TYPE_CHECKING, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import handles as handles_mod
 from repro.core.errors import HandleError, TaskError
 from repro.core.handles import AlMatrix
+from repro.core.payload import SlabPayload
+from repro.core.transport import StagedShards
 
 if TYPE_CHECKING:  # pragma: no cover - typing only, avoids an import cycle
     from repro.core.session import Session
@@ -70,9 +75,10 @@ class ResidentEntry:
         self.shape = tuple(int(d) for d in shape)
         self.dtype = dtype
         self.layout = layout
-        #: logical host bytes (row-major, unpadded) — None until a publisher
+        #: logical host bytes (row-major, unpadded): an ndarray, or the
+        #: adopted receive slabs (``SlabPayload``) — None until a publisher
         #: hands them over or a migration/attach fetches them.
-        self.payload: Optional[np.ndarray] = None
+        self.payload = None
         #: session id -> that session's placement handles (usually one).
         self.placements: Dict[int, List[AlMatrix]] = {}
         #: ids of sessions whose placement was *migrated* out (session close
@@ -151,6 +157,8 @@ class ResidentStore:
         self.attaches = 0
         self.migrations = 0
         self.evictions = 0
+        self.payload_adopted_bytes = 0
+        self.payload_copied_bytes = 0
 
     def __len__(self) -> int:
         with self._lock:
@@ -178,15 +186,21 @@ class ResidentStore:
         key: Tuple,
         handle: AlMatrix,
         session: "Session",
-        payload: Optional[np.ndarray] = None,
+        payload=None,
+        *,
+        copy: bool = False,
     ) -> ResidentEntry:
         """Publish a (possibly still pending) placement under ``key``.
 
         Called by the send path for the producing session and by the attach
         path for every subsequent one; idempotent per handle. ``payload`` —
-        the logical host bytes — is captured when the caller already owns a
-        private copy (the planner's snapshotted send arrays), making later
-        migration and cross-session placement free.
+        the logical host bytes — makes later migration and cross-session
+        placement free. The first one an entry sees is taken over without a
+        copy: received staging slabs (``StagedShards``) are adopted as they
+        lie, an ndarray is the caller's private copy (the planner's
+        snapshot). Only ``copy=True`` — an array its owner may still change —
+        costs a host copy. ``payload_adopted_bytes`` and
+        ``payload_copied_bytes`` count the two.
         """
         if not self.enabled:
             return ResidentEntry(key, handle.shape, handle.dtype, handle.layout)
@@ -197,7 +211,7 @@ class ResidentStore:
                 self._entries[key] = entry
                 self.publishes += 1
             if payload is not None and entry.payload is None:
-                entry.payload = np.asarray(payload)
+                entry.payload = self._take(payload, copy)
             hs = entry.placements.setdefault(session.id, [])
             if handle not in hs:
                 hs.append(handle)
@@ -209,6 +223,25 @@ class ResidentStore:
                 entry.device_ids = frozenset(d.id for d in devices)
             entry.last_use = next(_CLOCK)
             return entry
+
+    def _take(self, payload, copy: bool):
+        # caller holds self._lock
+        if copy and not isinstance(payload, StagedShards):
+            with TraceAnnotation("al.host.copy", nbytes=payload.nbytes, site="payload"):
+                taken = np.array(payload)
+            self.payload_copied_bytes += int(taken.nbytes)
+            return taken
+        taken = payload.adopt() if isinstance(payload, StagedShards) else np.asarray(payload)
+        self.payload_adopted_bytes += int(taken.nbytes)
+        return taken
+
+    def _drop(self, entry: ResidentEntry) -> None:
+        """The entry dies: forget its key, and give adopted receive slabs
+        back to the staging pool. Caller holds self._lock."""
+        self._entries.pop(entry.key, None)
+        if isinstance(entry.payload, SlabPayload):
+            entry.payload.release()
+        entry.payload = None
 
     def record_attach(self) -> None:
         with self._lock:
@@ -253,7 +286,7 @@ class ResidentStore:
                 if not hs:
                     del entry.placements[session_id]
             if entry.refcount == 0:
-                del self._entries[key]
+                self._drop(entry)
 
     def detach_session(self, session: "Session") -> int:
         """Session close: unpin every entry this session placed.
@@ -296,7 +329,8 @@ class ResidentStore:
         """Engine shutdown: drop every entry (placements were freed by their
         sessions' close)."""
         with self._lock:
-            self._entries.clear()
+            for entry in list(self._entries.values()):
+                self._drop(entry)
 
     # -- lineage recovery (DESIGN.md §14) ------------------------------------
     def recoverable_for(self, session_id: int) -> Dict[Tuple, ResidentEntry]:
@@ -342,15 +376,13 @@ class ResidentStore:
             local = self._entries.get(entry.key)
             if local is None:
                 local = ResidentEntry(entry.key, entry.shape, entry.dtype, entry.layout)
-                local.payload = entry.payload
                 self._entries[entry.key] = local
                 self.publishes += 1
-                adopted = True
-            elif local.payload is None:
+            adopted = local.payload is None
+            if adopted:
                 local.payload = entry.payload
-                adopted = True
-            else:
-                adopted = False
+                if isinstance(local.payload, SlabPayload):
+                    local.payload.retain()  # held by both stores now
             local.last_use = next(_CLOCK)
         self._enforce_retention()
         return adopted
@@ -428,7 +460,7 @@ class ResidentStore:
                 if held <= self.retain_bytes:
                     break
                 held -= e.nbytes()
-                del self._entries[e.key]
+                self._drop(e)
                 self.evictions += 1
 
     def stats(self) -> Dict[str, int]:
@@ -445,6 +477,8 @@ class ResidentStore:
                 "attaches": self.attaches,
                 "migrations": self.migrations,
                 "evictions": self.evictions,
+                "payload_adopted_bytes": self.payload_adopted_bytes,
+                "payload_copied_bytes": self.payload_copied_bytes,
             }
 
     def snapshot(self) -> Dict[Tuple, Dict]:

@@ -40,7 +40,9 @@ from jax.profiler import TraceAnnotation
 
 from repro.core import params as params_codec
 from repro.core.errors import ParameterError, SessionError, TaskError
+from repro.core.expr import raw_bytes
 from repro.core.futures import AlFuture
+from repro.core.payload import SlabPayload, aliases_host, join_rows
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from repro.core.client import ClientCore
@@ -319,7 +321,8 @@ class StagedShards:
     keep working; the send task assembles the sharded device array with
     ``jax.make_array_from_single_device_arrays`` — never a full-array
     reassembly copy. ``content_key()`` streams sha1 over the logical slab
-    views for the same reason."""
+    views for the same reason, and the resident store takes the slabs over
+    as its payload (``adopt``) instead of copying them."""
 
     ndim = 2
 
@@ -337,6 +340,8 @@ class StagedShards:
         #: transports hook this to fold overlap/put timings into wire stats.
         self.on_assembled = None
         self._assembled = False
+        #: the store payload these slabs became (``adopt``), if any.
+        self.adopted: Optional[SlabPayload] = None
 
     @property
     def shape(self) -> Tuple[int, int]:
@@ -358,13 +363,11 @@ class StagedShards:
             out.append(self.buffers[j][: e - s])
         return out
 
-    def __array__(self, dtype=None):
-        # Materialization fallback (attach payloads, non-staged consumers):
-        # the one deliberate full copy, never on the shard-direct hot path.
-        full = np.concatenate([s for s in self.logical_slabs() if s.size] or
-                              [np.empty((0, self.geom.shape[1]), self.dtype)], axis=0)
-        full = full.reshape(self.geom.shape)
-        return full.astype(dtype, copy=False) if dtype is not None else full
+    def __array__(self, dtype=None, copy=None):
+        # Materialization fallback for consumers that need one array (a
+        # stale geometry's classic send path): a full copy when there are
+        # several shards, never on the shard-direct hot path.
+        return join_rows(self.logical_slabs(), self.geom.shape, self.dtype, dtype, copy)
 
     def content_key(self) -> Tuple:
         """Streaming equivalent of :func:`repro.core.expr.content_key`: sha1
@@ -374,9 +377,19 @@ class StagedShards:
         h = hashlib.sha1()
         with TraceAnnotation("al.store.key", nbytes=self.nbytes, side="staged"):
             for slab in self.logical_slabs():
-                h.update(np.ascontiguousarray(slab).data)
+                h.update(raw_bytes(slab))
         r, c = self.geom.shape
         return ((int(r), int(c)), str(self.dtype), h.hexdigest())
+
+    def adopt(self) -> SlabPayload:
+        """Hand the slabs to the resident store as its payload, uncopied.
+        From here on ``dispose`` leaves them to the payload, which returns
+        them to the pool when the last store entry holding it dies."""
+        if self.adopted is None:
+            self.adopted = SlabPayload(
+                list(self.buffers), self.logical_slabs(), self.geom.shape, self.dtype, self._pool
+            )
+        return self.adopted
 
     def matches(self, layout, mesh) -> bool:
         return self.geom.matches(layout, mesh)
@@ -440,17 +453,20 @@ class StagedShards:
 
     def dispose(self, *check_arrays) -> None:
         """Return slabs to the staging pool — except any aliased by a live
-        device array (CPU ``device_put`` is zero-copy; see ``_aliases_host``)."""
-        if self._pool is None:
-            return
-        from repro.core.memgov import _aliases_host
-
+        device array (CPU ``device_put`` is zero-copy; see ``aliases_host``).
+        Adopted slabs stay with their store payload, which learns of the
+        aliased ones so it never pools them either."""
         live = [self._device[j] for j in range(len(self.buffers))]
         live.extend(a for a in check_arrays if a is not None)
+        if self.adopted is not None:
+            self.adopted.pin_aliases(*live)
+            return
+        if self._pool is None:
+            return
         for j, buf in enumerate(self.buffers):
             if buf is None:
                 continue
-            if any(a is not None and _aliases_host(a, buf) for a in live):
+            if any(a is not None and aliases_host(a, buf) for a in live):
                 continue
             self._pool.release(buf)
             self.buffers[j] = buf  # kept readable for logical views

@@ -465,14 +465,11 @@ class EngineServer:
             with TraceAnnotation("al.server.send", rid=rid):
                 arr, nread = self._recv_send_payload(conn, bound, rid)
                 self.stats["bytes_in"] += nread
-                payload = None
-                if bool(req.get("__has_payload")):
-                    # The offload planner wants a host snapshot for the content
-                    # store; staged payloads materialize here — the one place a
-                    # shard-direct receive pays a full host copy (documented:
-                    # plain sends, the hot path, never do).
-                    with TraceAnnotation("al.host.copy", nbytes=arr.nbytes, site="payload"):
-                        payload = np.asarray(arr)
+                # The offload planner wants a host payload for the content
+                # store: the received bytes are already private to this send,
+                # so the store adopts them as they lie — staging slabs and
+                # all — with no host copy (plain sends keep none at all).
+                payload = arr if bool(req.get("__has_payload")) else None
                 fut = core._local_submit_send(
                     arr,
                     name=str(req.get("__name") or ""),

@@ -42,3 +42,7 @@ def test_sharded_models_match_single_device():
 
 def test_queued_all_free_request_size_is_pinned():
     _run("_admission_script.py", "MULTIDEVICE_ADMISSION_OK")
+
+
+def test_multi_shard_payload_is_adopted_and_recycled():
+    _run("_adoption_script.py", "MULTIDEVICE_ADOPTION_OK")

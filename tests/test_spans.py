@@ -3,6 +3,7 @@ in-process TCP under the profiler, the trace read back, and each span found
 at its site with its stats. Spans of one request share the wire's ``rid``."""
 
 import glob
+import threading
 from collections import defaultdict
 
 import jax
@@ -11,6 +12,8 @@ import pytest
 from jax.profiler import ProfileData
 
 import repro
+from repro.core.expr import content_key
+from repro.core.scheduler import PlacementRequest
 from repro.core.taskqueue import TaskQueue
 
 ELEMENTAL = "repro.linalg.library:ElementalLib"
@@ -36,6 +39,7 @@ def traced(tmp_path_factory):
     rng = np.random.default_rng(0)
     a = rng.standard_normal((64, 48)).astype(np.float32)
     b = rng.standard_normal((48, 32)).astype(np.float32)
+    x = rng.standard_normal((40, 24))  # float64: no shard-direct receive
     engine = repro.AlchemistEngine()
     s = repro.connect(engine, transport="tcp")
     s.register_library("elemental", ELEMENTAL)
@@ -49,6 +53,7 @@ def traced(tmp_path_factory):
             out = c.data()
             for h in (c, la, lb):
                 h.free()
+            copied = _attach_fallback(x)
             jax.profiler.stop_trace()
         np.testing.assert_allclose(np.asarray(out), a @ b, rtol=1e-4, atol=1e-4)
         (snap,) = engine.stats()["sessions"].values()
@@ -58,9 +63,40 @@ def traced(tmp_path_factory):
             "b": b,
             "c": np.asarray(out),
             "waited": (waited, queue.stats()["wait_ns"], snap["tasks"]["wait_ns"]),
+            "x": x,
+            "copied": copied,
         }
     finally:
         s.close()
+
+
+def _attach_fallback(x: np.ndarray) -> int:
+    """The store's one host copy left, the attach fallback's, on its real
+    path: a second TCP session's send of ``x`` is decided as an attach, but
+    the producer frees the bytes before the attach task runs, so the
+    consumer publishes the array it received (an ndarray, as a float64
+    takes no shard-direct receive) with a copy. Returns the store's
+    ``payload_copied_bytes``."""
+    engine = repro.AlchemistEngine()
+    producer = repro.AlchemistContext(engine, num_workers=1, transport="tcp")
+    held = producer.send(x)  # a plain send: the entry keeps no payload
+    # The consumer joins the producer's worker group (one CPU device).
+    share = PlacementRequest(workers=1, affinity=[content_key(x)], allow_shared=True)
+    consumer = repro.connect(engine, placement=share, transport="tcp")
+    try:
+        gate = threading.Event()
+        consumer.session.tasks.submit(gate.wait, label="gate")
+        pending = consumer.send_async(x.copy())  # attach decided, task queued
+        entry = engine.residents.lookup(content_key(x))
+        assert entry.payload is None and consumer.session.id in entry.placements
+        producer.free(held)
+        gate.set()
+        placed = consumer.session.resolve(pending.result(30))  # no FETCH: read in place
+        np.testing.assert_allclose(np.asarray(placed.data()), x, rtol=1e-6)
+        return engine.residents.stats()["payload_copied_bytes"]
+    finally:
+        consumer.close()
+        producer.stop()
 
 
 def _named(spans, name, **stats):
@@ -112,10 +148,11 @@ def test_sends_carry_their_bytes_and_rid_across_threads(traced):
         (recv,) = _named(spans, "al.wire.recv", rid=rid)
         assert server[1] != write[1]  # the server's connection thread
         assert recv[4]["nbytes"] == x.nbytes and _inside(recv, server)
-        # The server's payload copy and content key run inside its SEND.
-        (copy,) = _named(spans, "al.host.copy", site="payload", nbytes=x.nbytes)
+        # The server's content key runs inside its SEND; its payload is the
+        # received slabs, adopted with no host copy.
         (key,) = _named(spans, "al.store.key", side="staged", nbytes=x.nbytes)
-        assert _inside(copy, server) and _inside(key, server)
+        assert _inside(key, server)
+        assert not [c for c in _named(spans, "al.host.copy", site="payload") if _inside(c, server)]
         # The client's snapshot and key run on the thread that writes.
         (snap,) = _named(spans, "al.host.copy", site="snapshot", nbytes=x.nbytes)
         (ckey,) = _named(spans, "al.store.key", side="host", nbytes=x.nbytes)
@@ -153,8 +190,15 @@ def test_task_spans_hold_their_work_and_wait(traced):
         if sp[0].startswith("al.task."):
             tasks[sp[0]].append(sp)
             assert sp[4]["queued_us"] >= 0
+    # The round trip's tasks, plus the attach fallback's: the producer's
+    # send and free, the consumer's gate and its attach.
     assert {k: len(v) for k, v in tasks.items()} == {
-        "al.task.send": 2, "al.task.run": 1, "al.task.collect": 1, "al.task.free": 3
+        "al.task.send": 2 + 1,
+        "al.task.run": 1,
+        "al.task.collect": 1,
+        "al.task.free": 3 + 1,
+        "al.task.gate": 1,
+        "al.task.attach": 1,
     }
     (routine,) = _named(spans, "al.routine")
     assert any(_inside(routine, t) for t in tasks["al.task.run"])
@@ -162,6 +206,13 @@ def test_task_spans_hold_their_work_and_wait(traced):
         assert any(_inside(rel, t) for t in tasks["al.task.send"])
     (rel,) = _named(spans, "al.relayout", direction="receive")
     assert any(_inside(rel, t) for t in tasks["al.task.collect"])
+
+
+def test_payload_copy_span_carries_the_copied_bytes(traced):
+    spans = traced["spans"]
+    (copy,) = _named(spans, "al.host.copy", site="payload")
+    assert copy[4]["nbytes"] == traced["copied"] == traced["x"].nbytes
+    assert any(_inside(copy, task) for task in _named(spans, "al.task.attach"))
 
 
 def test_queue_wait_counter_grows(traced):
