@@ -10,4 +10,4 @@ def read(run):
     if t is None or not run.jobs or t.busy_s <= 0:
         return None
     flops, nbytes = roofline.gemm_counts(cfg["m"], cfg["k"], cfg["n"])
-    return roofline.share_pct(flops, nbytes, run.device_kind, t.busy_s / run.jobs)
+    return roofline.share_pct(flops, nbytes, run.device_kind, t.busy_s / run.jobs, run.cell.chips)

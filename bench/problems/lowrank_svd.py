@@ -16,6 +16,7 @@ rounding of each element, some 1e-10 in sigma: far below every limit.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +40,7 @@ class Problem:
         g = rng(seed, 0)
         self.us = (np.linalg.qr(g.standard_normal((m, r)))[0] * self.sigma).astype(np.float32)
         self.vt = np.linalg.qr(g.standard_normal((n, r)))[0].T.astype(np.float32)
-        self.operands = {"A": product_on_device(self.us, self.vt)}
+        self.operands = {"A": product_on_device(self.us, self.vt, devices(cfg))}
         self.routine = Routine(
             library="elemental",
             name="truncated_svd",
@@ -78,16 +79,29 @@ def build(cfg: dict, seed: int) -> Problem:
     return Problem(cfg, seed)
 
 
-def product_on_device(us: np.ndarray, vt: np.ndarray) -> np.ndarray:
-    """fl32(us @ vt) on the host, made on the device in row blocks."""
+def devices(cfg: dict) -> list:
+    """The chips of the configuration's grid."""
+    return jax.devices()[: math.prod(cfg["grid"])]
+
+
+def product_on_device(us: np.ndarray, vt: np.ndarray, chips: list) -> np.ndarray:
+    """fl32(us @ vt) on the host, made in row blocks, one block on each chip
+    at a time, fetched together."""
     m, r = us.shape
     out = np.empty((m, vt.shape[1]), np.float32)
     rows = min(BLOCK_ROWS, m)
     pad = np.zeros((-m % rows, r), np.float32)
     us_p = np.concatenate([us, pad]) if len(pad) else us
-    vt_d = jax.device_put(vt)
-    for lo in range(0, m, rows):
-        out[lo : lo + rows] = np.asarray(_block(us_p[lo : lo + rows], vt_d))[: m - lo]
+    vts = [jax.device_put(vt, d) for d in chips]
+    starts = list(range(0, m, rows))
+    for i in range(0, len(starts), len(chips)):
+        group = starts[i : i + len(chips)]
+        blocks = [
+            _block(jax.device_put(us_p[lo : lo + rows], d), v)
+            for lo, d, v in zip(group, chips, vts)
+        ]
+        for lo, block in zip(group, jax.device_get(blocks)):
+            out[lo : lo + rows] = block[: m - lo]
     return out
 
 
@@ -157,21 +171,43 @@ def readings(ref: Exact, u, s, v) -> dict:
 def control_svd(problem: Problem, precision: str):
     """Golub-Kahan-Lanczos with full reorthogonalisation, written plainly, with
     every operand of every product rounded to ``precision`` (see
-    :mod:`bench.problems.precision`) and float32 accumulation."""
+    :mod:`bench.problems.precision`) and float32 accumulation.
+
+    A is made on the configuration's chips with its rows split evenly over
+    them, so a matrix that no one chip holds fits; the products are then
+    summed across chips as XLA partitions them."""
     cfg = problem.cfg
     steps = min(cfg["k"] + cfg["oversample"], cfg["m"], cfg["n"])
-    a = _block(problem.us, problem.vt)
-    out = _gkl(a, jax_key(problem.seed, 1), k=cfg["k"], steps=steps, precision=precision)
-    del a
+    a = rows_on(devices(cfg), problem.us, problem.vt)
+    aq = _round_rows(a, precision=precision)  # A's buffer becomes the rounded copy
+    out = _gkl(aq, jax_key(problem.seed, 1), k=cfg["k"], steps=steps, precision=precision)
+    del a, aq
     return tuple(np.asarray(x) for x in out)
 
 
+def rows_on(chips: list, us: np.ndarray, vt: np.ndarray):
+    """fl32(us @ vt) on ``chips``, its rows split evenly over them."""
+    if len(us) % len(chips):
+        raise ValueError(f"{len(us)} rows do not split evenly over {len(chips)} chips")
+    mesh = jax.sharding.Mesh(np.array(chips), ("rows",))
+    rows = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("rows", None))
+    whole = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    return jax.jit(_block, out_shardings=rows)(
+        jax.device_put(us, rows), jax.device_put(vt, whole)
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("precision",), donate_argnums=0)
+def _round_rows(a, *, precision: str):
+    """A stored once at ``precision``, per-row scaled, as a program would keep it."""
+    return rounder(precision)[0](a, axis=1)
+
+
 @functools.partial(jax.jit, static_argnames=("k", "steps", "precision"))
-def _gkl(a, key, *, k: int, steps: int, precision: str):
+def _gkl(aq, key, *, k: int, steps: int, precision: str):
     rnd, prec = rounder(precision)
     dot = functools.partial(jnp.dot, precision=prec, preferred_element_type=jnp.float32)
-    m, n = a.shape
-    aq = rnd(a, axis=1)  # stored once, per-row scaled, as a program would keep it
+    m, n = aq.shape
 
     def reorth(x, basis, valid):
         for _ in range(2):

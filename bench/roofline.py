@@ -46,8 +46,12 @@ def least_time_s(flops: float, nbytes: float, kind: str) -> float:
     return max(flops / p["bf16_flops_per_s"], nbytes / p["hbm_bytes_per_s"])
 
 
-def share_pct(flops: float, nbytes: float, kind: str, busy_s_per_job: float) -> float:
-    """Least time over the device's busy time per job, in percent."""
+def share_pct(
+    flops: float, nbytes: float, kind: str, busy_s_per_job: float, chips: int = 1
+) -> float:
+    """Least time over the device's busy time per job, in percent. On several
+    chips the problem's least time is taken at ``chips`` times the peaks, and
+    ``busy_s_per_job`` is the busy time per chip."""
     if busy_s_per_job <= 0:
         raise ValueError("a roofline share needs device time")
-    return 100.0 * least_time_s(flops, nbytes, kind) / busy_s_per_job
+    return 100.0 * (least_time_s(flops, nbytes, kind) / chips) / busy_s_per_job

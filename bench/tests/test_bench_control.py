@@ -5,9 +5,10 @@ cells' own size the same control runs on the chip (bench/calibrate.py)."""
 
 import pytest
 
-from bench import calibrate, harness, problems
+from bench import harness, problems
 from bench.faults import FAULTS
 from bench.tests.conftest import cell_names, small_cell
+from bench.tests.on_chips import UNEXCHANGED, on_its_chips
 
 SEED = 2**31 + 777
 
@@ -16,7 +17,7 @@ SEED = 2**31 + 777
 def test_control_fails_and_program_passes(rehearsal, name):
     cell = small_cell(name)
     precision = cell.config["control"]
-    rec = calibrate.readings(cell, SEED, 0.3, [precision])
+    rec = on_its_chips(name, "control", seed=SEED, seconds=0.3, precision=precision)
     limits = cell.config["limits"][rec["collect"]]
     program, control = rec["program"], rec[f"control_{precision}"]
     assert all(program[key] <= limit for key, limit in limits.items()), (program, limits)
@@ -35,19 +36,24 @@ def _faults():
 
 
 @pytest.mark.parametrize("name,routine,fault", _faults())
-def test_an_answer_altered_where_produced_is_not_correct(rehearsal, monkeypatch, name, routine,
-                                                         fault):
-    from repro.linalg.library import ElementalLib
+def test_an_answer_altered_where_produced_is_not_correct(rehearsal, name, routine, fault):
+    out = on_its_chips(name, "fault", seed=SEED + 1, seconds=0.3, routine=routine, fault=fault)
+    assert out["failed"] == 0 and not out["correct"], out["checks"]
 
-    cell = small_cell(name)
-    original = getattr(ElementalLib, f"_{routine}")
-    alter = FAULTS[routine][fault]
 
-    def altered(*args, mesh=None, **params):  # the engine passes a mesh to who names it
-        return alter(original(*args, mesh=mesh, **params))
+def _unexchanged():
+    out = []
+    for name in cell_names():
+        cell = small_cell(name)
+        routine = ROUTINES[cell.config["problem"]]
+        if cell.chips > 1 and routine in UNEXCHANGED:
+            out.append(pytest.param(name, routine, id=name))
+    return out
 
-    monkeypatch.setattr(ElementalLib, f"_{routine}", staticmethod(altered))
-    out = harness.run_cell(cell, seed=SEED + 1, seconds=0.3, trace=False, t0=0.0)
+
+@pytest.mark.parametrize("name,routine", _unexchanged())
+def test_the_exchange_between_chips_left_out_is_not_correct(rehearsal, name, routine):
+    out = on_its_chips(name, "unexchanged", seed=SEED + 3, seconds=0.3, routine=routine)
     assert out["failed"] == 0 and not out["correct"], out["checks"]
 
 

@@ -156,11 +156,77 @@ def test_bounds_and_sources(manifest):
     assert all(len(set(v)) == len(v) for v in layers.values())
 
 
+# A cell's set-up as measured on the chip, where it is longer than the 60 s a
+# run is allowed for it: ``bench/setup_s/<cell>.json`` holds ``{"setup_s": s}``
+# (with where it was measured); the benchmark's ledger, once it holds the cell,
+# gives its newest median too. A cell of more than one chip needs one of them.
+SETUP_DIR = os.path.join(ROOT, "bench", "setup_s")
+LEDGER = os.path.join(ROOT, "PERF_LEDGER.jsonl")
+
+
+def measured_setup_s(name: str, setup_dir: str = SETUP_DIR, ledger: str = LEDGER):
+    """The larger of the recorded and the ledger's newest set-up of cell
+    ``name``, in seconds; None where neither holds it."""
+    found = []
+    path = os.path.join(setup_dir, f"{name}.json")
+    if os.path.exists(path):
+        with open(path) as f:
+            found.append(float(json.load(f)["setup_s"]))
+    newest = None
+    if os.path.exists(ledger):
+        with open(ledger) as f:
+            for line in f:
+                entry = json.loads(line)
+                value = ((entry.get("end_to_end") or {}).get("setup_s") or [None])[-1]
+                if entry.get("workload") == name and value is not None:
+                    newest = float(value)
+    if newest is not None:
+        found.append(newest)
+    return max(found) if found else None
+
+
+def full_check_s(run_seconds: int, cells: list) -> float:
+    """Chip seconds of a full check of ``cells`` [(chips, setup_s or None)],
+    padded with one-chip cells to 24: 2 + 14 runs per cell, each of
+    run_seconds plus 60 s or the cell's own set-up where that is longer, and
+    2 x 90 s per cell to compile; a four-chip cell's count four times. 1200 s
+    spare. A cell of several chips with no measured set-up is refused."""
+    total = 0.0
+    for chips, setup_s in cells + [(1, None)] * (24 - len(cells)):
+        if chips > 1 and setup_s is None:
+            raise LookupError(f"a cell of {chips} chips needs its measured set-up in {SETUP_DIR}")
+        total += chips * (14 * (run_seconds + max(60.0, setup_s or 0.0)) + 2 * 90)
+    return total + 2 * (run_seconds + 60) + 1200
+
+
 def test_a_full_check_fits_its_time(manifest):
-    # 2 + 14 runs per cell of run_seconds + 60 s each, 2 x 90 s per cell to
-    # compile and 1200 s spare, for the 24 cells later PRs may reach.
-    cells = 24
-    seconds = (2 + 14 * cells) * (manifest["run_seconds"] + 60) + cells * 180 + 1200
-    assert seconds <= 43200
+    cells = [(c["chips"], measured_setup_s(c["name"])) for c in manifest["workloads"]]
+    assert full_check_s(manifest["run_seconds"], cells) <= 43200
     four = sum(w["chips"] == 4 for w in manifest["workloads"])
     assert four <= max(1, math.floor(len(manifest["workloads"]) / 2))
+
+
+def test_a_cell_of_several_chips_is_priced_at_its_measured_setup(tmp_path):
+    (tmp_path / "big.json").write_text(json.dumps({"setup_s": 441.5}))
+    none = str(tmp_path / "no_ledger.jsonl")
+    assert measured_setup_s("big", str(tmp_path), none) == 441.5
+    assert measured_setup_s("small", str(tmp_path), none) is None
+    with pytest.raises(LookupError):
+        full_check_s(20, [(4, measured_setup_s("small", str(tmp_path), none))])
+    # Four chips at 441.5 s of set-up beside 23 one-chip cells overrun the check.
+    assert full_check_s(20, [(4, 441.5)]) > 43200
+    assert full_check_s(20, [(4, 60.0)]) <= 43200
+
+
+def test_the_ledgers_newest_setup_counts(tmp_path):
+    ledger = tmp_path / "ledger.jsonl"
+    lines = [
+        {"workload": "c", "end_to_end": {"setup_s": [None, 90.0]}},
+        {"workload": "d", "end_to_end": {"setup_s": [1.0, 500.0]}},
+        {"workload": "c", "end_to_end": {"setup_s": [90.0, 70.0]}},
+        {"workload": None},
+    ]
+    ledger.write_text("".join(json.dumps(x) + "\n" for x in lines))
+    assert measured_setup_s("c", str(tmp_path), str(ledger)) == 70.0
+    (tmp_path / "c.json").write_text(json.dumps({"setup_s": 80.0}))
+    assert measured_setup_s("c", str(tmp_path), str(ledger)) == 80.0

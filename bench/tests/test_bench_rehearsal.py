@@ -12,6 +12,7 @@ import pytest
 
 from bench import harness, problems
 from bench.tests.conftest import ROOT, cell_names, small_cell
+from bench.tests.on_chips import on_its_chips
 
 SEED = 2**31 + 12345  # past 32 signed bits: any whole seed is taken
 
@@ -19,7 +20,7 @@ SEED = 2**31 + 12345  # past 32 signed bits: any whole seed is taken
 @pytest.mark.parametrize("name", cell_names())
 def test_cell_runs_and_checks_correct(rehearsal, name):
     cell = small_cell(name)
-    out = harness.run_cell(cell, seed=SEED, seconds=0.5, trace=False, t0=0.0)
+    out = on_its_chips(name, "run", seed=SEED, seconds=0.5, trace=False)
     assert out["correct"], out["checks"]
     assert out["attempted"] >= 1 and out["failed"] == 0
     assert set(out["metrics"]) == {m["name"] for m in cell.end_to_end}
@@ -32,11 +33,11 @@ def test_cell_runs_and_checks_correct(rehearsal, name):
 @pytest.mark.parametrize("name", cell_names())
 def test_traced_run_reads_its_layers(rehearsal, name):
     cell = small_cell(name)
-    rooflines = [m for m in cell.per_layer if m["name"].endswith("_roofline")]
-    cell.per_layer = [m for m in cell.per_layer if m not in rooflines]
-    out = harness.run_cell(cell, seed=SEED + 1, seconds=0.5, trace=True, t0=0.0)
+    rooflines = [m["name"] for m in cell.per_layer if m["name"].endswith("_roofline")]
+    layers = [m["name"] for m in cell.per_layer if m["name"] not in rooflines]
+    out = on_its_chips(name, "run", seed=SEED + 1, seconds=0.5, trace=True, metrics=layers)
     assert out["correct"], out["checks"]
-    assert set(out["metrics"]) == {m["name"] for m in cell.per_layer}
+    assert set(out["metrics"]) == set(layers)
     assert 0 < out["device"]["busy_s"] < out["device"]["window_s"]
     ops, idle = out["breakdown"]["device_ops"], out["breakdown"]["idle_gaps"]
     assert 0 < len(ops) <= 10 and 0 < len(idle) <= 10
@@ -44,9 +45,8 @@ def test_traced_run_reads_its_layers(rehearsal, name):
                                           "bench.free", "bench.between"}
     if rooflines:
         # A roofline needs the peaks of a known chip: never one of the CPU's.
-        cell.per_layer = rooflines
-        with pytest.raises(KeyError, match="no peaks"):
-            harness.run_cell(cell, seed=SEED + 2, seconds=0.2, trace=True, t0=0.0)
+        out = on_its_chips(name, "run", seed=SEED + 2, seconds=0.2, trace=True, metrics=rooflines)
+        assert "no peaks" in out["error"]
 
 
 def test_seeds_give_the_same_operands(rehearsal):

@@ -46,3 +46,15 @@ def test_a_share_for_a_described_kind_needs_no_chip():
     assert roofline.share_pct(flops, nbytes, V5E, 2 * least) == pytest.approx(50.0)
     with pytest.raises(ValueError):
         roofline.share_pct(flops, nbytes, V5E, 0.0)
+
+
+def test_a_problem_shared_by_four_chips_reads_the_same_share():
+    # Four chips hold four times the peaks, and each is busy for a quarter
+    # of the time one chip would be: the share is the same.
+    flops, nbytes = roofline.truncated_svd_counts(625_000, 10_000, 20)
+    least = roofline.least_time_s(flops, nbytes, V5E)
+    one = roofline.share_pct(flops, nbytes, V5E, 3 * least)
+    assert one == pytest.approx(100 / 3)
+    assert roofline.share_pct(flops, nbytes, V5E, 3 * least / 4, chips=4) == pytest.approx(one)
+    # At one chip the reading is the one the formula always gave, bit for bit.
+    assert roofline.share_pct(flops, nbytes, V5E, 0.3, chips=1) == 100.0 * least / 0.3

@@ -211,6 +211,7 @@ def test_tpu_roundtrip_trace_reduces_to_its_recorded_numbers():
     assert got.top_ops(1)[0] == ("repro_tiled_matmul.1", pytest.approx(0.02290458, abs=1e-9))
     assert got.verb_n == {"bench.send": 2, "bench.run": 1, "bench.collect": 1, "bench.free": 3}
     assert got.idle_s["bench.send"] == pytest.approx(4.045769456, abs=1e-9)
+    assert got.chips == 1 and got.collective_s == 0
 
 
 @pytest.mark.parametrize(

@@ -3,6 +3,13 @@
 - Device busy time: the union of the intervals in which an operation ran on
   a device, inside the window (the harness's ``bench.window`` span), averaged
   over the chips the cell uses.
+- Collective time: the part of each chip's busy time in which a collective
+  was in flight (an ``all-reduce``, ``all-gather``, ``reduce-scatter``,
+  ``collective-permute`` or ``all-to-all``; an async ``-start.N``/``-done.N``
+  pair from the start of the one to the end of the other), inside the
+  window, averaged over the chips. A pair's stretches with no op running on
+  the chip are idle time, not collective time, so the share never passes
+  the busy time.
 - Device time by operation: summed durations, per op name.
 - Idle time by host activity: each idle interval of the device, split by the
   harness's verb spans (``bench.send``, ``bench.run``, ``bench.collect``,
@@ -13,8 +20,11 @@
   its own time.
 
 On a TPU the operations are the events of the ``XLA Ops`` line of each
-``/device:TPU:<i>`` plane. A trace with no device plane (the CPU backend,
-in the rehearsals) takes the host events that carry an ``hlo_op`` stat.
+``/device:TPU:<i>`` plane that holds some, ``i`` below the cell's chip count
+where that is given: a cell's group is the host's first chips, and a trace
+in which another number of them ran ops is refused. A trace with
+no device plane (the CPU backend, in the rehearsals) takes the host events
+that carry an ``hlo_op`` stat.
 """
 
 from __future__ import annotations
@@ -27,12 +37,15 @@ from dataclasses import dataclass, field
 WINDOW = "bench.window"
 VERB_PREFIX = "bench."
 BETWEEN = "bench.between"
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "collective-permute", "all-to-all")
 
 
 @dataclass
 class Summary:
     window_s: float
     busy_s: float  # per chip, averaged over the chips used
+    chips: int = 1  # the chips whose ops were read
+    collective_s: float = 0.0  # busy time with a collective in flight, per chip, averaged
     op_s: dict = field(default_factory=dict)  # op name -> seconds, summed over chips
     idle_s: dict = field(default_factory=dict)  # host activity -> seconds, mean over chips
     verb_n: dict = field(default_factory=dict)  # verb -> spans in the window
@@ -94,6 +107,29 @@ def overlap(spans: list, busy: list) -> list:
     return out
 
 
+def collective_intervals(ops: list) -> list:
+    """The (start, end) intervals of one chip's ``(name, start, end)`` ops in
+    which a collective was in flight: a synchronous one's own interval, and
+    an async pair's from its ``<kind>-start.N`` to the next ``<kind>-done.N``
+    of the same ``N`` (none for a bare ``-start``/``-done``)."""
+    out, started = [], defaultdict(list)
+    for name, s, e in sorted(ops, key=lambda op: op[1]):
+        kind = next((c for c in COLLECTIVES if name.startswith(c)), None)
+        if kind is None:
+            continue
+        rest = name[len(kind) :]
+        half = next((h for h in ("-start", "-done") if rest.startswith(h)), None)
+        if half is None:
+            out.append((s, e))
+            continue
+        pending = started[kind, rest[len(half) :]]
+        if half == "-start":
+            pending.append(s)
+        elif pending:
+            out.append((pending.pop(0), e))
+    return out
+
+
 def _stat(event, key):
     for k, v in event.stats:
         if k == key:
@@ -107,21 +143,25 @@ def op_name(text: str) -> str:
     return text.split(" = ", 1)[0].lstrip("%")
 
 
-def _device_events(pd, chips: int) -> dict:
-    """{device index: [(name, start_ns, end_ns), ...]}"""
+def _device_events(pd, chips: int | None = None) -> dict:
+    """{device index: [(name, start_ns, end_ns), ...]} of each TPU that ran an
+    op, ``chips`` of them at most where given (a cell's group is the host's
+    first chips)."""
     devices: dict = {}
     for plane in pd.planes:
         if not plane.name.startswith("/device:TPU:"):
             continue
         idx = int(plane.name.rsplit(":", 1)[1])
-        if idx >= chips:
+        if chips is not None and idx >= chips:
             continue
-        evs = devices.setdefault(idx, [])
-        for line in plane.lines:
-            if line.name == "XLA Ops":
-                evs.extend(
-                    (op_name(e.name), e.start_ns, e.start_ns + e.duration_ns) for e in line.events
-                )
+        evs = [
+            (op_name(e.name), e.start_ns, e.start_ns + e.duration_ns)
+            for line in plane.lines
+            if line.name == "XLA Ops"
+            for e in line.events
+        ]
+        if evs:
+            devices[idx] = evs
     if devices:
         return devices
     host = []  # no device plane: the CPU backend's ops, on host threads
@@ -145,7 +185,7 @@ def _host_spans(pd) -> list:
     return out
 
 
-def reduce(path: str, chips: int = 1) -> Summary:
+def reduce(path: str, chips: int | None = None) -> Summary:
     """Summarise the trace at ``path`` over its ``bench.window`` span."""
     from jax.profiler import ProfileData
 
@@ -155,7 +195,11 @@ def reduce(path: str, chips: int = 1) -> Summary:
     if len(windows) != 1:
         raise ValueError(f"expected one {WINDOW} span in the trace, found {len(windows)}")
     verbs = [span for span in spans if span[0] != WINDOW]
-    return summarize(windows[0], verbs, list(_device_events(pd, chips).values()))
+    devices = _device_events(pd, chips)
+    on_tpu = any(plane.name.startswith("/device:TPU:") for plane in pd.planes)
+    if on_tpu and chips is not None and len(devices) != chips:
+        raise ValueError(f"the trace holds the ops of {len(devices)} chips, the cell has {chips}")
+    return summarize(windows[0], verbs, list(devices.values()))
 
 
 def summarize(window: tuple, verbs: list, devices: list) -> Summary:
@@ -164,7 +208,8 @@ def summarize(window: tuple, verbs: list, devices: list) -> Summary:
     lo, hi = window
     verbs = sorted((s, e, name) for name, s, e in verbs)
     verbs = [(s, e, name) for s, e, name in verbs if s >= lo and e <= hi]
-    busy_total, op_s, idle_s = 0.0, defaultdict(float), defaultdict(float)
+    busy_total, collective_total = 0.0, 0.0
+    op_s, idle_s = defaultdict(float), defaultdict(float)
     verb_n, verb_host_s = defaultdict(int), defaultdict(float)
     for _, _, name in verbs:
         verb_n[name] += 1
@@ -174,6 +219,8 @@ def summarize(window: tuple, verbs: list, devices: list) -> Summary:
             op_s[name] += (e - s) / 1e9
         busy = union([(s, e) for _, s, e in inside])
         busy_total += sum(e - s for s, e in busy) / 1e9
+        collective = union(clip(collective_intervals(evs), lo, hi))
+        collective_total += sum(overlap(collective, busy)) / 1e9
         covered = overlap([(s, e) for s, e, _ in verbs], busy)
         for (s, e, name), c in zip(verbs, covered):
             verb_host_s[name] += (e - s - c) / 1e9 / len(devices)
@@ -192,6 +239,8 @@ def summarize(window: tuple, verbs: list, devices: list) -> Summary:
     return Summary(
         window_s=(hi - lo) / 1e9,
         busy_s=busy_total / len(devices),
+        chips=len(devices),
+        collective_s=collective_total / len(devices),
         op_s=dict(op_s),
         idle_s=dict(idle_s),
         verb_n=dict(verb_n),
